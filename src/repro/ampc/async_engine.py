@@ -275,17 +275,23 @@ class AsyncEngineMixin:
         self._async_observe_submit(spec.name)
         while True:
             # bounded-queue backpressure, but never wedge on a pool that
-            # was shut down underneath a blocked producer
-            try:
-                q.put(fut, timeout=0.1)
-                return fut
-            except queue.Full:
-                with self._async_lock:
-                    if self._async_closed:
-                        fut.cancel()
-                        raise RuntimeError(
-                            "engine shut down while submit was blocked on "
-                            "a full queue") from None
+            # was shut down underneath a blocked producer.  A future enters
+            # the queue only under the lock and while the engine is open,
+            # so shutdown's stop markers always queue behind every accepted
+            # future (a put racing them could land behind the last marker
+            # and never be served)
+            with self._async_lock:
+                if self._async_closed:
+                    fut.cancel()
+                    raise RuntimeError(
+                        "engine shut down while submit was blocked on "
+                        "a full queue")
+                try:
+                    q.put_nowait(fut)
+                    return fut
+                except queue.Full:
+                    pass
+            time.sleep(0.005)
 
     def submit_many(self, graphs: Sequence[Any], problem: str,
                     **kwargs) -> List[AmpcFuture]:

@@ -212,8 +212,9 @@ class AmpcEngine(AsyncEngineMixin):
     >>> res = eng.solve(gen.erdos_renyi(32, 2.0, seed=2), "mis")
     >>> res.trace.name, res.trace.attributes["problem"]
     ('solve', 'mis')
-    >>> [c.name for c in res.trace.children]
-    ['shuffle:DirectEdges+WriteKV', 'shuffle:IsInMIS']
+    >>> [c.name for c in res.trace.children]  # doctest: +NORMALIZE_WHITESPACE
+    ['wait:launch_lock', 'host:rank', 'shuffle:DirectEdges+WriteKV',
+     'shuffle:IsInMIS', 'host:assemble']
 
     Async serving (``submit`` -> future) and snapshot reuse on one graph
     (``session``; see ``repro.ampc.session``):
@@ -275,6 +276,18 @@ class AmpcEngine(AsyncEngineMixin):
                   labelnames=("problem", "backend", "mode")).inc(
                       1, problem=spec.name, backend=self.dht.name, mode=mode)
 
+    @contextlib.contextmanager
+    def _locked(self, tracer):
+        """Hold the launch lock, its acquisition timed as the span
+        ``wait:launch_lock`` (the time a solve queues behind other device
+        work, which the async pool's ``queue_wait`` does not see)."""
+        with tracer.span("wait:launch_lock"):
+            self._launch_lock.__enter__()
+        try:
+            yield
+        finally:
+            self._launch_lock.__exit__(None, None, None)
+
     # ------------------------------------------------------------------
     def _validate(self, spec, graph) -> None:
         if spec.needs_weights and getattr(graph, "weights", None) is None:
@@ -312,12 +325,12 @@ class AmpcEngine(AsyncEngineMixin):
         span = None
         t0 = time.perf_counter()
         # the launch lock serializes device work across async workers; the
-        # wait for it is part of the solve span (device-contention time)
+        # wait for it is the solve's wait:launch_lock span
         if tracer.enabled:
             with tracer.span("solve", problem=spec.name, model=spec.model,
                              backend=self.dht.name, n=int(graph.n),
                              m=int(graph.m)) as span:
-                with self._launch_lock:
+                with self._locked(tracer):
                     output, stats = spec.fn(ctx, graph, **opts)
         else:
             with self._launch_lock:
@@ -377,7 +390,9 @@ class AmpcEngine(AsyncEngineMixin):
         if root is not None:
             root.__enter__()
         try:
-            for batch in batching.bucketize(graphs).values():
+            with tracer.span("host:pad"):
+                buckets = batching.bucketize(graphs)
+            for batch in buckets.values():
                 self._solve_bucket(spec, batch, results, rec,
                                    seed=seed, epsilon=epsilon, **opts)
         finally:
@@ -409,7 +424,7 @@ class AmpcEngine(AsyncEngineMixin):
         t0 = time.perf_counter()
         if bspan is not None:
             with bspan:
-                with self._launch_lock:
+                with self._locked(tracer):
                     outs = spec.batch_fn(bctx, batch, **opts)
         else:
             with self._launch_lock:

@@ -49,6 +49,7 @@ from ..core.connectivity import (_canonicalize, _cc_fixpoint_masked,
                                  _h2m_phase)
 from ..core.one_vs_two import cycle_adjacency, _walk_and_count, \
     _walk_and_count_batch, _local_contraction_phase
+from ..obs.trace import current_tracer
 from . import registry
 from .registry import batched_impl, problem
 
@@ -88,22 +89,26 @@ def mis_ampc(g: UGraph, seed: int = 0,
     ledger = ledger if ledger is not None else RoundLedger("ampc_mis")
     n = g.n
     rng = np.random.default_rng(seed)
-    rank = rng.permutation(n).astype(np.float32)
+    with ledger.host("rank"):
+        rank = rng.permutation(n).astype(np.float32)
 
     snap_stat = None
     if snapshot is not None:
         entries, snap_hit = snapshot.materialize(ledger)
         senders = entries["sym_senders"]
         receivers = entries["sym_receivers"]
-        jrank = jnp.asarray(rank)
+        with ledger.host("to_device"):
+            jrank = jnp.asarray(rank)
         snap_stat = snapshot.stat(snap_hit)
     else:
         # shuffle 1: build the rank-directed graph, write to the DHT
         # (Fig 1 step 1-2)
         with ledger.shuffle("DirectEdges+WriteKV", nbytes_of(g.edges) * 2):
-            s, r, _, _ = g.symmetric()
-            senders = jnp.asarray(s); receivers = jnp.asarray(r)
-            jrank = jnp.asarray(rank)
+            with ledger.host("symmetric"):
+                s, r, _, _ = g.symmetric()
+            with ledger.host("to_device"):
+                senders = jnp.asarray(s); receivers = jnp.asarray(r)
+                jrank = jnp.asarray(rank)
 
     # shuffle 2: IsInMIS search — adaptive queries against the snapshot
     with ledger.shuffle("IsInMIS", n * 4):
@@ -111,18 +116,20 @@ def mis_ampc(g: UGraph, seed: int = 0,
         out_dev = _collect_dev(dht, ledger, status_dev)
         # the solve's one transfer: outputs + every deferred counter record
         status, it, qn, qd = ledger.harvest((out_dev, iters, q0, q1))
-        status = np.asarray(status)
-        it, qn, qd = int(it), int(qn), int(qd)
-    queries = qd if caching else qn
-    row_bytes = 8  # nodeid + status
-    ledger.record_queries(queries, queries * row_bytes, waves=it,
-                          deduped_away=(qn - qd) if caching else 0)
-    assert not (status == UNKNOWN).any()
-    stats = {"fixpoint_iters": it, "queries_nodedup": qn,
-             "queries_dedup": qd,
-             "cache_savings_factor": qn / max(qd, 1)}
-    if snap_stat is not None:
-        stats["snapshot"] = snap_stat
+        with ledger.host("assemble"):
+            status = np.asarray(status)
+            it, qn, qd = int(it), int(qn), int(qd)
+    with ledger.host("assemble"):
+        queries = qd if caching else qn
+        row_bytes = 8  # nodeid + status
+        ledger.record_queries(queries, queries * row_bytes, waves=it,
+                              deduped_away=(qn - qd) if caching else 0)
+        assert not (status == UNKNOWN).any()
+        stats = {"fixpoint_iters": it, "queries_nodedup": qn,
+                 "queries_dedup": qd,
+                 "cache_savings_factor": qn / max(qd, 1)}
+        if snap_stat is not None:
+            stats["snapshot"] = snap_stat
     return status == IN, stats
 
 
@@ -185,7 +192,8 @@ def mm_ampc(g: UGraph, seed: int = 0,
     n, m = g.n, g.m
     if erank is None:
         rng = np.random.default_rng(seed)
-        erank = rng.permutation(m).astype(np.float32)
+        with ledger.host("rank"):
+            erank = rng.permutation(m).astype(np.float32)
     else:
         erank = np.asarray(erank, np.float32)
         assert erank.shape == (m,), "erank must be one rank per edge"
@@ -194,27 +202,31 @@ def mm_ampc(g: UGraph, seed: int = 0,
     if snapshot is not None:
         entries, snap_hit = snapshot.materialize(ledger)
         u = entries["edge_u"]; v = entries["edge_v"]
-        jrank = jnp.asarray(erank)
+        with ledger.host("to_device"):
+            jrank = jnp.asarray(erank)
         snap_stat = snapshot.stat(snap_hit)
     else:
         with ledger.shuffle("SortEdges+WriteKV", nbytes_of(g.edges) * 2):
-            u = jnp.asarray(g.edges[:, 0]); v = jnp.asarray(g.edges[:, 1])
-            jrank = jnp.asarray(erank)
+            with ledger.host("to_device"):
+                u = jnp.asarray(g.edges[:, 0]); v = jnp.asarray(g.edges[:, 1])
+                jrank = jnp.asarray(erank)
 
     with ledger.shuffle("IsInMM", m):
         estatus_dev, iters, q0, q1 = _mm_fixpoint(
             u, v, jrank, n, jnp.zeros((m,), jnp.int32))
         out_dev = _collect_dev(dht, ledger, estatus_dev)
         estatus, it, qn, qd = ledger.harvest((out_dev, iters, q0, q1))
-        estatus = np.asarray(estatus)
-        it, qn, qd = int(it), int(qn), int(qd)
-    queries = qd if caching else qn
-    ledger.record_queries(queries, queries * 12, waves=it,
-                          deduped_away=(qn - qd) if caching else 0)
-    stats = {"fixpoint_iters": it, "queries_nodedup": qn,
-             "queries_dedup": qd, "erank": erank}
-    if snap_stat is not None:
-        stats["snapshot"] = snap_stat
+        with ledger.host("assemble"):
+            estatus = np.asarray(estatus)
+            it, qn, qd = int(it), int(qn), int(qd)
+    with ledger.host("assemble"):
+        queries = qd if caching else qn
+        ledger.record_queries(queries, queries * 12, waves=it,
+                              deduped_away=(qn - qd) if caching else 0)
+        stats = {"fixpoint_iters": it, "queries_nodedup": qn,
+                 "queries_dedup": qd, "erank": erank}
+        if snap_stat is not None:
+            stats["snapshot"] = snap_stat
     return estatus == IN, stats
 
 
@@ -419,6 +431,15 @@ def _msf_assemble(orig_eid, m, dmask, found_h, q_h, jump_h, live_h, phases_h,
     return mask, stats
 
 
+def _lane_stats(lane_steps_h, lane_steps_run_h) -> dict:
+    """Truncated Prim's lane counters (per chunk, see
+    ``truncated_prim_capped``) summed into ``stats`` keys: the search
+    loop's useful lane-iterations and all it ran."""
+    return {"prim_lane_steps": int(np.sum(lane_steps_h, dtype=np.int64)),
+            "prim_lane_steps_run": int(np.sum(lane_steps_run_h,
+                                              dtype=np.int64))}
+
+
 def msf_ampc(g: UGraph, epsilon: float = 0.5, seed: int = 0,
              ledger: Optional[RoundLedger] = None,
              skip_ternarize_if_dense: bool = True,
@@ -446,8 +467,9 @@ def msf_ampc(g: UGraph, epsilon: float = 0.5, seed: int = 0,
             u, v, w = entries["edge_u"], entries["edge_v"], entries["edge_w"]
             shuffle_nbytes = 0  # the write was accounted at view build
         else:
-            u = jnp.asarray(g.edges[:, 0]); v = jnp.asarray(g.edges[:, 1])
-            w = jnp.asarray(g.weights)
+            with ledger.host("to_device"):
+                u = jnp.asarray(g.edges[:, 0]); v = jnp.asarray(g.edges[:, 1])
+                w = jnp.asarray(g.weights)
             shuffle_nbytes = nbytes_of(g.edges, g.weights)
         eid = jnp.arange(m, dtype=jnp.int32)
         valid = jnp.ones((m,), bool)
@@ -455,7 +477,8 @@ def msf_ampc(g: UGraph, epsilon: float = 0.5, seed: int = 0,
             mask_dev, _, phases = boruvka_inround(u, v, w, eid, valid, n, m)
             col_dev = _collect_dev(dht, ledger, mask_dev.astype(jnp.int32))
             mask, phases_h = ledger.harvest((col_dev, phases))
-            mask = np.asarray(mask).astype(bool)
+            with ledger.host("assemble"):
+                mask = np.asarray(mask).astype(bool)
         stats = {"phases": int(phases_h), "path": "dense"}
         if snapshot is not None:
             stats["snapshot"] = snapshot.stat(snap_hit)
@@ -467,12 +490,15 @@ def msf_ampc(g: UGraph, epsilon: float = 0.5, seed: int = 0,
         entries, snap_hit = snapshot.materialize_tern(ledger)
         tg = entries["tg"]
         nt = tg.g.n
-        rank = rng.permutation(nt).astype(np.float32)
+        with ledger.host("rank"):
+            rank = rng.permutation(nt).astype(np.float32)
         budget = max(2, int(np.ceil(nt ** (epsilon / 2.0))))
         with ledger.shuffle("MSF", 0):
-            found, hooks, cases, queries = truncated_prim(
+            with ledger.host("to_device"):
+                jn_rank = jnp.asarray(rank)
+            found, hooks, cases, queries, steps, steps_run = truncated_prim(
                 entries["nbr"], entries["nbw"], entries["nbe"],
-                jnp.asarray(rank), budget, tg.g.m)
+                jn_rank, budget, tg.g.m)
             q_sum = queries.sum()
             ledger.record_queries_deferred(q_sum, q_sum * 36, waves=1)
             parent = jnp.where(hooks >= 0, hooks,
@@ -486,30 +512,39 @@ def msf_ampc(g: UGraph, epsilon: float = 0.5, seed: int = 0,
             dmask_dev, _, phases = boruvka_inround(cu, cv, cw, ceid, cvalid,
                                                    nt, max(m, 1))
             col_dev = _collect_dev(dht, ledger, dmask_dev.astype(jnp.int32))
-            (dmask, found_h, q_h, jump_h, live_h, phases_h, cases_h) = \
-                ledger.harvest((col_dev, found, q_sum, jump_iters, live,
-                                phases, cases))
-            dmask = np.asarray(dmask).astype(bool)
-        mask, stats = _msf_assemble(tg.orig_eid, m, dmask, found_h, q_h,
-                                    jump_h, live_h, phases_h, cases_h,
-                                    budget, nt)
-        stats["snapshot"] = snapshot.stat(snap_hit)
+            (dmask, found_h, q_h, jump_h, live_h, phases_h, cases_h,
+             steps_h, steps_run_h) = ledger.harvest(
+                (col_dev, found, q_sum, jump_iters, live, phases, cases,
+                 steps, steps_run))
+            with ledger.host("assemble"):
+                dmask = np.asarray(dmask).astype(bool)
+        with ledger.host("assemble"):
+            mask, stats = _msf_assemble(tg.orig_eid, m, dmask, found_h, q_h,
+                                        jump_h, live_h, phases_h, cases_h,
+                                        budget, nt)
+            stats.update(_lane_stats(steps_h, steps_run_h))
+            stats["snapshot"] = snapshot.stat(snap_hit)
         return mask, stats
 
     # --- shuffle 1: SortGraph (ternarize + build sorted adjacency, write DHT)
     with ledger.shuffle("SortGraph", nbytes_of(g.edges, g.weights)):
-        tg = ternarize(g)
-        nbr, nbw, nbe = tg.g.padded_adj(3)
+        with ledger.host("ternarize"):
+            tg = ternarize(g)
+        with ledger.host("adjacency"):
+            nbr, nbw, nbe = tg.g.padded_adj(3)
         nt = tg.g.n
-        rank = rng.permutation(nt).astype(np.float32)
+        with ledger.host("rank"):
+            rank = rng.permutation(nt).astype(np.float32)
         budget = max(2, int(np.ceil(nt ** (epsilon / 2.0))))
     ledger.record_queries(0, 0, waves=0)
 
     # --- shuffle 2: PrimSearch (adaptive queries against the DHT snapshot)
-    jn_nbr, jn_nbw, jn_nbe = jnp.asarray(nbr), jnp.asarray(nbw), jnp.asarray(nbe)
-    jn_rank = jnp.asarray(rank)
+    with ledger.host("to_device"):
+        jn_nbr, jn_nbw, jn_nbe = (jnp.asarray(nbr), jnp.asarray(nbw),
+                                  jnp.asarray(nbe))
+        jn_rank = jnp.asarray(rank)
     with ledger.shuffle("PrimSearch", 0):
-        found, hooks, cases, queries = truncated_prim(
+        found, hooks, cases, queries, steps, steps_run = truncated_prim(
             jn_nbr, jn_nbw, jn_nbe, jn_rank, budget, tg.g.m)
         q_sum = queries.sum()
     row_bytes = 3 * (4 + 4 + 4)
@@ -523,8 +558,9 @@ def msf_ampc(g: UGraph, epsilon: float = 0.5, seed: int = 0,
                                    waves=1)
 
     # --- shuffle 4: Contract (relabel + dedup on the ternarized edge list)
-    tu = jnp.asarray(tg.g.edges[:, 0]); tv = jnp.asarray(tg.g.edges[:, 1])
-    tw = jnp.asarray(tg.g.weights); teid = jnp.asarray(tg.orig_eid)
+    with ledger.host("to_device"):
+        tu = jnp.asarray(tg.g.edges[:, 0]); tv = jnp.asarray(tg.g.edges[:, 1])
+        tw = jnp.asarray(tg.g.weights); teid = jnp.asarray(tg.orig_eid)
     with ledger.shuffle("Contract", nbytes_of(tg.g.edges, tg.g.weights)):
         cu, cv, cw, ceid, cvalid, live = contract_edges(
             tu, tv, tw, teid, jnp.ones((tg.g.m,), bool), roots)
@@ -535,12 +571,18 @@ def msf_ampc(g: UGraph, epsilon: float = 0.5, seed: int = 0,
         dmask_dev, dlabels, phases = boruvka_inround(cu, cv, cw, ceid, cvalid,
                                                      nt, max(m, 1))
         col_dev = _collect_dev(dht, ledger, dmask_dev.astype(jnp.int32))
-        (dmask, found_h, q_h, jump_h, live_h, phases_h, cases_h) = \
-            ledger.harvest((col_dev, found, q_sum, jump_iters, live,
-                            phases, cases))
-        dmask = np.asarray(dmask).astype(bool)
-    return _msf_assemble(tg.orig_eid, m, dmask, found_h, q_h, jump_h, live_h,
-                         phases_h, cases_h, budget, nt)
+        (dmask, found_h, q_h, jump_h, live_h, phases_h, cases_h, steps_h,
+         steps_run_h) = ledger.harvest(
+            (col_dev, found, q_sum, jump_iters, live, phases, cases, steps,
+             steps_run))
+        with ledger.host("assemble"):
+            dmask = np.asarray(dmask).astype(bool)
+    with ledger.host("assemble"):
+        mask, stats = _msf_assemble(tg.orig_eid, m, dmask, found_h, q_h,
+                                    jump_h, live_h, phases_h, cases_h,
+                                    budget, nt)
+        stats.update(_lane_stats(steps_h, steps_run_h))
+    return mask, stats
 
 
 def msf_mpc_boruvka(g: UGraph, seed: int = 0,
@@ -601,12 +643,15 @@ def cc_ampc(g: UGraph, epsilon: float = 0.5, seed: int = 0,
         entries, snap_hit = snapshot.materialize_tern(ledger, unit=True)
         tg = entries["tg"]
         nt = tg.g.n
-        rank = rng.permutation(nt).astype(np.float32)
+        with ledger.host("rank"):
+            rank = rng.permutation(nt).astype(np.float32)
         budget = max(2, int(np.ceil(nt ** (epsilon / 2.0))))
         with ledger.shuffle("Connectivity", 0):
-            _, hooks, cases, queries = truncated_prim(
+            with ledger.host("to_device"):
+                jn_rank = jnp.asarray(rank)
+            _, hooks, cases, queries, steps, steps_run = truncated_prim(
                 entries["nbr"], entries["nbw"], entries["nbe"],
-                jnp.asarray(rank), budget, tg.g.m)
+                jn_rank, budget, tg.g.m)
             q_sum = queries.sum()
             ledger.record_queries_deferred(q_sum, q_sum * 36, waves=1)
             parent = jnp.where(hooks >= 0, hooks,
@@ -625,32 +670,42 @@ def cc_ampc(g: UGraph, epsilon: float = 0.5, seed: int = 0,
             else:
                 final_tern = jnp.take(dlabels, roots)
                 orig_dev = jnp.take(final_tern, entries["first_slot"])
-            orig_labels, q_h, jump_h, phases_h = \
-                ledger.harvest((orig_dev, q_sum, jump_iters, phases))
-            orig_labels = np.asarray(orig_labels).astype(np.int64)
-        labels = _canonicalize(orig_labels)
-        return labels, {
-            "queries": int(q_h),
-            "pointer_jump_iters": int(jump_h),
-            "dense_phases": int(phases_h),
-            "num_components": int(len(np.unique(labels))),
-            "snapshot": snapshot.stat(snap_hit),
-        }
+            orig_labels, q_h, jump_h, phases_h, steps_h, steps_run_h = \
+                ledger.harvest((orig_dev, q_sum, jump_iters, phases, steps,
+                                steps_run))
+            with ledger.host("assemble"):
+                orig_labels = np.asarray(orig_labels).astype(np.int64)
+        with ledger.host("assemble"):
+            labels = _canonicalize(orig_labels)
+            stats = {
+                "queries": int(q_h),
+                "pointer_jump_iters": int(jump_h),
+                "dense_phases": int(phases_h),
+                "num_components": int(len(np.unique(labels))),
+                **_lane_stats(steps_h, steps_run_h),
+                "snapshot": snapshot.stat(snap_hit),
+            }
+        return labels, stats
 
     gw = UGraph(n, g.edges, np.arange(m, dtype=np.float32))  # unit-ish distinct
     with ledger.shuffle("SortGraph", nbytes_of(gw.edges)):
-        tg = ternarize(gw)
-        nbr, nbw, nbe = tg.g.padded_adj(3)
+        with ledger.host("ternarize"):
+            tg = ternarize(gw)
+        with ledger.host("adjacency"):
+            nbr, nbw, nbe = tg.g.padded_adj(3)
         nt = tg.g.n
-        rank = rng.permutation(nt).astype(np.float32)
+        with ledger.host("rank"):
+            rank = rng.permutation(nt).astype(np.float32)
         budget = max(2, int(np.ceil(nt ** (epsilon / 2.0))))
         # first tern slot of each original vertex (node_of is sorted)
-        first_slot = np.searchsorted(tg.node_of, np.arange(n))
+        with ledger.host("adjacency"):
+            first_slot = np.searchsorted(tg.node_of, np.arange(n))
 
     with ledger.shuffle("PrimSearch", 0):
-        _, hooks, cases, queries = truncated_prim(
-            jnp.asarray(nbr), jnp.asarray(nbw), jnp.asarray(nbe),
-            jnp.asarray(rank), budget, tg.g.m)
+        with ledger.host("to_device"):
+            adj = [jnp.asarray(a) for a in (nbr, nbw, nbe, rank)]
+        _, hooks, cases, queries, steps, steps_run = truncated_prim(
+            *adj, budget, tg.g.m)
         q_sum = queries.sum()
     ledger.record_queries_deferred(q_sum, q_sum * 36, waves=1)
 
@@ -658,8 +713,9 @@ def cc_ampc(g: UGraph, epsilon: float = 0.5, seed: int = 0,
         parent = jnp.where(hooks >= 0, hooks, jnp.arange(nt, dtype=jnp.int32))
         roots, jump_iters = pointer_jump(parent)
 
-    tu = jnp.asarray(tg.g.edges[:, 0]); tv = jnp.asarray(tg.g.edges[:, 1])
-    tw = jnp.asarray(tg.g.weights); teid = jnp.asarray(tg.orig_eid)
+    with ledger.host("to_device"):
+        tu = jnp.asarray(tg.g.edges[:, 0]); tv = jnp.asarray(tg.g.edges[:, 1])
+        tw = jnp.asarray(tg.g.weights); teid = jnp.asarray(tg.orig_eid)
     with ledger.shuffle("Contract", nbytes_of(tg.g.edges)):
         cu, cv, cw, ceid, cvalid, live = contract_edges(
             tu, tv, tw, teid, jnp.ones((tg.g.m,), bool), roots)
@@ -670,23 +726,29 @@ def cc_ampc(g: UGraph, epsilon: float = 0.5, seed: int = 0,
         # compose contractions: two genuine DHT reads of the label maps
         if dht is not None:
             final_tern = dht.lookup(dlabels, roots, ledger=ledger)
-            orig_dev = dht.lookup(final_tern,
-                                  jnp.asarray(first_slot, jnp.int32),
-                                  ledger=ledger)
+            with ledger.host("to_device"):
+                jn_first = jnp.asarray(first_slot, jnp.int32)
+            orig_dev = dht.lookup(final_tern, jn_first, ledger=ledger)
         else:
             final_tern = jnp.take(dlabels, roots)
-            orig_dev = jnp.take(final_tern, jnp.asarray(first_slot))
-        orig_labels, q_h, jump_h, phases_h = \
-            ledger.harvest((orig_dev, q_sum, jump_iters, phases))
-        orig_labels = np.asarray(orig_labels).astype(np.int64)
+            with ledger.host("to_device"):
+                jn_first = jnp.asarray(first_slot)
+            orig_dev = jnp.take(final_tern, jn_first)
+        orig_labels, q_h, jump_h, phases_h, steps_h, steps_run_h = \
+            ledger.harvest((orig_dev, q_sum, jump_iters, phases, steps,
+                            steps_run))
+        with ledger.host("assemble"):
+            orig_labels = np.asarray(orig_labels).astype(np.int64)
 
-    labels = _canonicalize(orig_labels)
-    stats = {
-        "queries": int(q_h),
-        "pointer_jump_iters": int(jump_h),
-        "dense_phases": int(phases_h),
-        "num_components": int(len(np.unique(labels))),
-    }
+    with ledger.host("assemble"):
+        labels = _canonicalize(orig_labels)
+        stats = {
+            "queries": int(q_h),
+            "pointer_jump_iters": int(jump_h),
+            "dense_phases": int(phases_h),
+            "num_components": int(len(np.unique(labels))),
+            **_lane_stats(steps_h, steps_run_h),
+        }
     return labels, stats
 
 
@@ -1088,8 +1150,10 @@ def _build_msf_sparse_solver(ntb: int, mtb: int, mb: int, capacity: int):
     ``mb`` its *original* edge capacity — the Borůvka mask is over
     original edge ids (``teid``), exactly like the sequential path."""
     def one(nbr, nbw, nbe, rank, budget, nmask, tu, tv, tw, teid, emask):
-        found, hooks, cases, queries = truncated_prim_capped(
-            nbr, nbw, nbe, rank, budget, capacity, mtb)
+        # the lane's real tern vertices are the first nmask.sum()
+        found, hooks, cases, queries, steps, steps_run = \
+            truncated_prim_capped(nbr, nbw, nbe, rank, budget, capacity, mtb,
+                                  nmask.sum())
         # padded tern vertices exhaust on their first frontier pop; mask
         # their unit query out of the per-graph total
         q_sum = jnp.where(nmask, queries, 0).sum()
@@ -1100,7 +1164,7 @@ def _build_msf_sparse_solver(ntb: int, mtb: int, mb: int, capacity: int):
             tu, tv, tw, teid, emask, roots)
         dmask, _, phases = boruvka_core(cu, cv, cw, ceid, cvalid, ntb, mb)
         return (dmask.astype(jnp.int32), found, q_sum, jump_iters,
-                live, phases, cases)
+                live, phases, cases, steps, steps_run)
 
     return jax.jit(jax.vmap(one))
 
@@ -1139,7 +1203,8 @@ def msf_ampc_batched(bctx, batch, skip_ternarize_if_dense: bool = True):
     t0 = time.perf_counter()
     sparse_extra = dense_extra = None
     if sparse_idx:
-        tb = ternarize_batch([batch.graphs[b] for b in sparse_idx])
+        with current_tracer().span("host:pad"):
+            tb = ternarize_batch([batch.graphs[b] for b in sparse_idx])
         Bs, ntb = len(tb), tb.nt_bucket
         ranks = np.zeros((Bs, ntb), np.float32)
         budgets = np.zeros((Bs,), np.int32)
@@ -1160,7 +1225,8 @@ def msf_ampc_batched(bctx, batch, skip_ternarize_if_dense: bool = True):
             skey, lambda: _build_msf_sparse_solver(ntb, tb.mt_bucket, mb,
                                                    capacity),
             occupants=Bs)
-        (dmask_b, found_b, q_b, jump_b, live_b, phases_b, cases_b) = ssolver(
+        (dmask_b, found_b, q_b, jump_b, live_b, phases_b, cases_b, steps_b,
+         steps_run_b) = ssolver(
             jnp.asarray(tb.nbr), jnp.asarray(tb.nbw), jnp.asarray(tb.nbe),
             jnp.asarray(ranks), jnp.asarray(budgets),
             jnp.asarray(tb.node_mask), jnp.asarray(tb.edges[:, :, 0]),
@@ -1179,7 +1245,7 @@ def msf_ampc_batched(bctx, batch, skip_ternarize_if_dense: bool = True):
             dmask_b, keys, ledgers=[bctx.ledgers[b] for b in sparse_idx],
             key_mask=batch.edge_mask[np.asarray(sparse_idx)])
         sparse_extra = (col_b, found_b, q_b, jump_b, live_b, phases_b,
-                        cases_b)
+                        cases_b, steps_b, steps_run_b)
     if dense_idx:
         didx = np.asarray(dense_idx)
         demask = batch.edge_mask[didx]
@@ -1206,7 +1272,8 @@ def msf_ampc_batched(bctx, batch, skip_ternarize_if_dense: bool = True):
 
     outs = [None] * B
     if sparse_idx:
-        (col_h, found_h, q_h, jump_h, live_h, phases_h, cases_h) = sparse_h
+        (col_h, found_h, q_h, jump_h, live_h, phases_h, cases_h, steps_h,
+         steps_run_h) = sparse_h
         col_h = np.asarray(col_h)
         found_h = np.asarray(found_h)
         cases_h = np.asarray(cases_h)
@@ -1224,6 +1291,7 @@ def msf_ampc_batched(bctx, batch, skip_ternarize_if_dense: bool = True):
                 t.orig_eid, g.m, col_h[j, :g.m].astype(bool),
                 found_h[j, :t.g.m], q_h[j], jump_h[j], live_h[j], phases_h[j],
                 cases_h[j, :nt], int(budgets[j]), nt)
+            stats.update(_lane_stats(steps_h[j], steps_run_h[j]))
             stats["solver_cache"] = _cache_stat(skey, shit, j)
             outs[b] = (mask, stats)
     if dense_idx:

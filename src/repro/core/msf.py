@@ -41,7 +41,7 @@ _PRIM_STATE_BYTES = 1 << 28   # per-chunk frontier state of truncated Prim
 # --------------------------------------------------------------------------
 @functools.partial(jax.jit, static_argnames=("capacity", "n_edges"))
 def truncated_prim_capped(nbr, nbw, nbe, rank, budget, capacity: int,
-                          n_edges: int):
+                          n_edges: int, n_real=None):
     """``truncated_prim`` with the buffer *capacity* decoupled from the
     stopping *budget*.
 
@@ -53,6 +53,15 @@ def truncated_prim_capped(nbr, nbw, nbe, rank, budget, capacity: int,
     never win the frontier argmin, so outputs are still bit-identical.  This
     is what lets a vmapped batch of graphs share one compiled program while
     each lane keeps its own n-dependent budget.
+
+    Besides ``truncated_prim``'s outputs it counts the search loop's lanes,
+    one int32 per chunk of vertices (below): ``lane_steps``, the loop
+    iterations of the searches from real vertices (those below ``n_real``,
+    a traced count that defaults to ``n``: a padded bucket passes its
+    graph's own vertex count), and ``lane_steps_run``, ``chunk`` times the
+    longest search of the chunk: every lane-iteration the vmapped loop ran,
+    padding lanes included.  Their sums' ratio is the share of the loop's
+    lanes that did useful work.
     """
     n, D = nbr.shape
     F = D * capacity  # frontier capacity
@@ -77,7 +86,8 @@ def truncated_prim_capped(nbr, nbw, nbe, rank, budget, capacity: int,
         out = jnp.full((capacity,), -1, jnp.int32)
         st = dict(visited=visited, vcount=jnp.int32(1), fdst=fdst, fw=fw,
                   feid=feid, fsize=jnp.int32(D), out=out, ocount=jnp.int32(0),
-                  hook=jnp.int32(-1), case=jnp.int32(0), queries=jnp.int32(1))
+                  hook=jnp.int32(-1), case=jnp.int32(0), queries=jnp.int32(1),
+                  steps=jnp.int32(0))
 
         def cond(s):
             return s["case"] == 0
@@ -135,10 +145,11 @@ def truncated_prim_capped(nbr, nbw, nbe, rank, budget, capacity: int,
                 "hook": jnp.where(hook, dst, s["hook"]),
                 "case": case,
                 "queries": s["queries"] + took.astype(jnp.int32),
+                "steps": s["steps"] + 1,
             }
 
         s = jax.lax.while_loop(cond, body, st)
-        return s["out"], s["hook"], s["case"], s["queries"]
+        return s["out"], s["hook"], s["case"], s["queries"], s["steps"]
 
     # vertices run in vmapped chunks, so the live search state stays near
     # _PRIM_STATE_BYTES however many vertices the graph has, and each
@@ -151,18 +162,23 @@ def truncated_prim_capped(nbr, nbw, nbe, rank, budget, capacity: int,
     verts = jnp.arange(n_chunks * chunk, dtype=jnp.int32).reshape(
         n_chunks, chunk)
 
+    real_n = n if n_real is None else n_real
+
     def run_chunk(found, vs):
-        out, hook, case, queries = jax.vmap(per_vertex)(
+        out, hook, case, queries, steps = jax.vmap(per_vertex)(
             jnp.minimum(vs, n - 1))
         live = (vs < n)[:, None] & (out >= 0)
         found = found.at[jnp.where(live, out, n_edges).ravel()].set(
             True, mode="drop")
-        return found, (hook, case, queries)
+        # a chunk's sums stay below 2^31 (chunk x 3 x capacity steps at
+        # most); the per-chunk values are summed on the host
+        lane_steps = jnp.where(vs < real_n, steps, 0).sum()
+        return found, (hook, case, queries, lane_steps, chunk * steps.max())
 
-    found, (hooks, cases, queries) = jax.lax.scan(
-        run_chunk, jnp.zeros((n_edges,), bool), verts)
+    found, (hooks, cases, queries, lane_steps, lane_steps_run) = \
+        jax.lax.scan(run_chunk, jnp.zeros((n_edges,), bool), verts)
     return (found, hooks.reshape(-1)[:n], cases.reshape(-1)[:n],
-            queries.reshape(-1)[:n])
+            queries.reshape(-1)[:n], lane_steps, lane_steps_run)
 
 
 @functools.partial(jax.jit, static_argnames=("budget", "n_edges"))
@@ -173,7 +189,9 @@ def truncated_prim(nbr, nbw, nbe, rank, budget: int, n_edges: int):
     rank: (n,) distinct float ranks (the random permutation π).
     n_edges: number of edge ids (every id in nbe is below it).
     Returns (found (n_edges,) bool — the edges some search took, hooks (n,),
-    cases (n,), queries (n,)).
+    cases (n,), queries (n,), lane_steps (chunks,), lane_steps_run
+    (chunks,)); the last two count the search loop's lanes (see
+    ``truncated_prim_capped``).
     cases: 1 = budget hit, 2 = component exhausted, 3 = lower-rank hook.
     """
     return truncated_prim_capped(nbr, nbw, nbe, rank,

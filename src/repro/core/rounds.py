@@ -17,7 +17,10 @@ Observability wiring (``repro.obs``): a ledger may carry a ``tracer`` and a
 ``shuffle:<name>``, carrying its bytes) and every counter update lands in
 the engine-wide metric series (``shuffles_total``, ``dht_queries_total``,
 …) labeled by ``algorithm``.  Both default to disabled no-ops, so a bare
-``RoundLedger`` behaves exactly as before.
+``RoundLedger`` behaves exactly as before.  Beside the shuffles, a traced
+ledger opens ``host:<stage>`` spans around the solve's host work
+(:meth:`RoundLedger.host`) and a ``device:wait`` span around each harvest
+transfer, where the host blocks on the device; neither counts anything.
 
 Raw-string event accumulation is gated behind ``record_events``: the
 structured trace supersedes the strings, and long-lived engines serving
@@ -46,11 +49,34 @@ import dataclasses
 import time
 from typing import Any, Dict, List, Optional, Sequence
 
+from ..obs.trace import NOOP_SPAN, current_tracer
+
 # Test hook for the one-harvest-per-solve rule: when set, called with the
 # ledger (or ledger list) each time a harvest performs its single
 # ``jax.device_get``.  Tests install a counting callback to assert a warm
 # solve syncs exactly once.
 HARVEST_HOOK: Any = None
+
+
+def _device_wait(tracer):
+    """The span ``device:wait`` around one transfer to the host: the host
+    blocked on the device.  The shared no-op span without a tracer."""
+    if tracer is not None and tracer.enabled:
+        return tracer.span("device:wait")
+    return NOOP_SPAN
+
+
+def _get_each(extra, tracer):
+    """``extra``'s host copy, one blocking transfer per leaf (the eager
+    ledgers' sync pattern), each under its own ``device:wait``."""
+    import jax
+
+    leaves, treedef = jax.tree.flatten(extra)
+    host = []
+    for leaf in leaves:
+        with _device_wait(tracer):
+            host.append(jax.device_get(leaf))
+    return jax.tree.unflatten(treedef, host)
 
 
 class DeviceCounters:
@@ -123,6 +149,16 @@ class RoundLedger:
             yield
         self._count_shuffle(name, nbytes, time.perf_counter() - t0)
 
+    def host(self, stage: str):
+        """A span ``host:<stage>`` around host work of the solve: rank
+        draws, ternarize, adjacency, host-to-device copies, assembly after
+        the harvest.  It counts nothing (no shuffle, no ``phase_times``);
+        without a tracer it is the shared no-op span."""
+        tracer = self.tracer
+        if tracer is not None and tracer.enabled:
+            return tracer.span(f"host:{stage}")
+        return NOOP_SPAN
+
     def record_shuffle(self, name: str, nbytes: int = 0,
                        seconds: float = 0.0):
         """Record one materialized round without timing a ``with`` block.
@@ -188,9 +224,10 @@ class RoundLedger:
         ``extra`` is an optional pytree of device arrays the caller wants
         pulled in the same ``jax.device_get`` (solver outputs, iteration
         counters); its host copy is returned.  This is the *one* host
-        sync a deferred solve performs; :data:`HARVEST_HOOK` fires once
-        per actual transfer so tests can count syncs.  With nothing
-        pending and no ``extra`` the call is free — no transfer at all.
+        sync a deferred solve performs, under a ``device:wait`` span;
+        :data:`HARVEST_HOOK` fires once per actual transfer so tests can
+        count syncs.  With nothing pending and no ``extra`` the call is
+        free — no transfer at all.
 
         On an eager (``deferred=False``) ledger this instead mirrors the
         pre-deferral sync pattern: one blocking ``jax.device_get`` per
@@ -210,11 +247,10 @@ class RoundLedger:
         if not self.deferred and extra is not None:
             # records were already applied eagerly at record time, so only
             # extra remains; transfer leaf by leaf (seed sync pattern)
-            leaves, treedef = jax.tree.flatten(extra)
-            host = [jax.device_get(leaf) for leaf in leaves]
-            return jax.tree.unflatten(treedef, host)
-        host_records, host_extra = jax.device_get(
-            ([rec for rec, _ in records], extra))
+            return _get_each(extra, self.tracer)
+        with _device_wait(self.tracer):
+            host_records, host_extra = jax.device_get(
+                ([rec for rec, _ in records], extra))
         for host_rec, (_, span) in zip(host_records, records):
             self._apply_queries(*(int(x) for x in host_rec), span=span)
         return host_extra
@@ -278,6 +314,8 @@ def harvest_many(ledgers: Sequence[Optional[RoundLedger]], extra=None):
     bucket launch accumulates pending records on every per-graph ledger,
     and the engine drains them all — plus the batched outputs in
     ``extra`` — with a single transfer.  Returns ``extra``'s host copy.
+    Bucket ledgers carry no tracer, so the transfer's ``device:wait`` span
+    goes to the tracer of the span open on this thread (the ``bucket``).
     """
     import jax
 
@@ -291,11 +329,10 @@ def harvest_many(ledgers: Sequence[Optional[RoundLedger]], extra=None):
         # all-eager bucket: mirror the pre-deferral per-leaf sync pattern
         # (see RoundLedger.harvest) so eager solve_many stays a faithful
         # baseline
-        leaves, treedef = jax.tree.flatten(extra)
-        return jax.tree.unflatten(treedef,
-                                  [jax.device_get(leaf) for leaf in leaves])
-    host_pending, host_extra = jax.device_get(
-        ([[rec for rec, _ in records] for records in pending], extra))
+        return _get_each(extra, current_tracer())
+    with _device_wait(current_tracer()):
+        host_pending, host_extra = jax.device_get(
+            ([[rec for rec, _ in records] for records in pending], extra))
     for led, host_records, records in zip(ledgers, host_pending, pending):
         for host_rec, (_, span) in zip(host_records, records):
             led._apply_queries(*(int(x) for x in host_rec), span=span)

@@ -4,8 +4,9 @@
   ``chrome://tracing`` and https://ui.perfetto.dev: complete events
   (``ph="X"`` with ``ts``/``dur`` in microseconds) for spans, instant
   events (``ph="i"``) for span events, and ``ph="M"`` metadata records.
-* :func:`write_jsonl` — one JSON object per span (flat, parent-linked),
-  for ad-hoc ``jq``/pandas analysis of engine timelines.
+* :func:`write_jsonl` — one JSON object per span (flat, linked by
+  ``parent_id`` and ``request_id``), for ad-hoc ``jq``/pandas analysis of
+  engine timelines.
 * :func:`metrics_report` — plain-text registry dump
   (``engine.metrics_report()`` delegates here).
 """
@@ -58,7 +59,9 @@ def to_chrome_trace(tracer_or_spans, *, pid: Optional[int] = None,
         events.append({
             "name": sp.name, "ph": "X", "ts": sp.ts_us, "dur": sp.dur_us,
             "pid": pid, "tid": tid, "cat": "span",
-            "args": _args(sp.attributes),
+            "args": {**_args(sp.attributes), "span_id": sp.span_id,
+                     "parent_id": sp.parent_id,
+                     "request_id": sp.request_id},
         })
         for ev in sp.events:
             events.append({
@@ -66,11 +69,6 @@ def to_chrome_trace(tracer_or_spans, *, pid: Optional[int] = None,
                 "tid": tid, "s": "t", "cat": ev.level,
                 "args": _args(ev.attributes),
             })
-    if hasattr(tracer_or_spans, "orphan_events"):
-        for ev in tracer_or_spans.orphan_events():
-            events.append({"name": ev.name, "ph": "i", "ts": ev.ts_us,
-                           "pid": pid, "tid": 0, "s": "p", "cat": ev.level,
-                           "args": _args(ev.attributes)})
     main_tid = threading.main_thread().ident
     for tid in sorted(tids):
         label = "main" if tid == main_tid else f"thread-{tid}"
@@ -91,9 +89,10 @@ def write_chrome_trace(path: str, tracer_or_spans, *,
     return doc
 
 
-def span_to_dict(sp: Span, parent_id: Optional[int] = None) -> dict:
+def span_to_dict(sp: Span) -> dict:
     return {
-        "span_id": sp.span_id, "parent_id": parent_id, "name": sp.name,
+        "span_id": sp.span_id, "parent_id": sp.parent_id,
+        "request_id": sp.request_id, "name": sp.name,
         "ts_us": sp.ts_us, "dur_us": sp.dur_us, "thread_id": sp.thread_id,
         "attributes": _args(sp.attributes),
         "events": [{"name": ev.name, "ts_us": ev.ts_us, "level": ev.level,
@@ -105,13 +104,9 @@ def write_jsonl(path: str, tracer_or_spans) -> int:
     """Write one JSON object per span; returns the number of lines."""
     n = 0
     with open(path, "w") as f:
-        stack = [(root, None) for root in reversed(_roots(tracer_or_spans))]
-        while stack:
-            sp, parent_id = stack.pop()
-            f.write(json.dumps(span_to_dict(sp, parent_id)) + "\n")
+        for sp in iter_spans(tracer_or_spans):
+            f.write(json.dumps(span_to_dict(sp)) + "\n")
             n += 1
-            for c in reversed(sp.children):
-                stack.append((c, sp.span_id))
     return n
 
 

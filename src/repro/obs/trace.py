@@ -18,9 +18,16 @@ Design constraints (why this is not just ``logging``):
 Timestamps are microseconds since a process-wide epoch (monotonic), which
 is exactly what the Chrome-trace exporter needs.
 
+Span identity: every span carries its ``span_id``, its parent's id
+(``parent_id``, None for a root) and a ``request_id``, the id of its root
+span, so every span of one request shares one identifier.
+
 Optional device bridging: ``Tracer(annotate_device=True)`` additionally
 wraps every span in a ``jax.profiler.TraceAnnotation`` so the same span
-names show up inside device profiles captured with ``jax.profiler.trace``.
+names show up inside device profiles captured with ``jax.profiler.trace``;
+the annotation carries ``span_id``, ``parent_id`` and ``request_id`` as
+stats, so each host event of the profile joins its span on the profile's
+own clock.
 """
 from __future__ import annotations
 
@@ -60,13 +67,16 @@ class Span:
     duration was measured externally (the batched ``solve_many`` path).
     """
 
-    __slots__ = ("name", "span_id", "ts_us", "dur_us", "thread_id",
-                 "attributes", "events", "children", "_tracer", "_annotation")
+    __slots__ = ("name", "span_id", "parent_id", "request_id", "ts_us",
+                 "dur_us", "thread_id", "attributes", "events", "children",
+                 "_tracer", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str,
                  attributes: Optional[Dict[str, Any]] = None):
         self.name = name
         self.span_id = next(tracer._ids)
+        self.parent_id: Optional[int] = None
+        self.request_id = self.span_id    # until it is placed under a parent
         self.ts_us = 0
         self.dur_us = 0
         self.thread_id = 0
@@ -81,7 +91,7 @@ class Span:
         self._tracer._push(self)
         self.thread_id = threading.get_ident()
         if self._tracer.annotate_device:
-            self._annotation = self._tracer._enter_annotation(self.name)
+            self._annotation = self._tracer._enter_annotation(self)
         self.ts_us = _now_us()
         return self
 
@@ -103,6 +113,11 @@ class Span:
 
     def event(self, name: str, level: str = "INFO", **attributes) -> None:
         self.events.append(SpanEvent(name, _now_us(), level, attributes))
+
+    def _adopt(self, child: "Span") -> None:
+        self.children.append(child)
+        child.parent_id = self.span_id
+        child.request_id = self.request_id
 
     # -- inspection --------------------------------------------------------
     @property
@@ -134,7 +149,6 @@ class Tracer:
         self._ids = itertools.count(1)
         self._lock = threading.Lock()
         self._roots: List[Span] = []
-        self._orphan_events: List[SpanEvent] = []
         self._local = threading.local()
 
     # -- span lifecycle ----------------------------------------------------
@@ -156,25 +170,22 @@ class Tracer:
         sp.dur_us = int(dur_s * 1e6)
         sp.ts_us = _now_us() - sp.dur_us
         if parent is not None:
-            parent.children.append(sp)
+            parent._adopt(sp)
         else:
             stack = getattr(self._local, "stack", None)
             if stack:
-                stack[-1].children.append(sp)
+                stack[-1]._adopt(sp)
             else:
                 with self._lock:
                     self._roots.append(sp)
         return sp
 
     def event(self, name: str, level: str = "INFO", **attributes) -> None:
-        """Attach an event to the current span (or the tracer itself)."""
+        """Attach an event to the current span; with no span open on this
+        thread the event is dropped."""
         stack = getattr(self._local, "stack", None)
         if stack:
             stack[-1].event(name, level=level, **attributes)
-        else:
-            with self._lock:
-                self._orphan_events.append(
-                    SpanEvent(name, _now_us(), level, attributes))
 
     def current_span(self) -> Optional[Span]:
         """The innermost open span on this thread, if any.
@@ -194,7 +205,7 @@ class Tracer:
         if stack is None:
             stack = self._local.stack = []
         if stack:
-            stack[-1].children.append(span)
+            stack[-1]._adopt(span)
         stack.append(span)
         _active_stack().append(self)
 
@@ -210,10 +221,13 @@ class Tracer:
             with self._lock:
                 self._roots.append(span)
 
-    def _enter_annotation(self, name: str):
+    def _enter_annotation(self, span: Span):
+        ids = {"span_id": span.span_id, "request_id": span.request_id}
+        if span.parent_id is not None:
+            ids["parent_id"] = span.parent_id
         try:  # pragma: no cover - depends on jax profiler availability
             from jax.profiler import TraceAnnotation
-            ann = TraceAnnotation(name)
+            ann = TraceAnnotation(span.name, **ids)
             ann.__enter__()
             return ann
         except Exception:
@@ -229,14 +243,9 @@ class Tracer:
         """Flat snapshot of every completed span, depth-first."""
         return [s for root in self.spans() for s in root.walk()]
 
-    def orphan_events(self) -> List[SpanEvent]:
-        with self._lock:
-            return list(self._orphan_events)
-
     def clear(self) -> None:
         with self._lock:
             self._roots.clear()
-            self._orphan_events.clear()
 
     def __repr__(self):
         return f"Tracer(roots={len(self.spans())})"
@@ -302,9 +311,6 @@ class NoopTracer:
         return []
 
     def all_spans(self):
-        return []
-
-    def orphan_events(self):
         return []
 
     def clear(self):

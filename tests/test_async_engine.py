@@ -259,6 +259,41 @@ def test_shutdown_drain_false_cancels_queued():
     eng.shutdown()  # idempotent
 
 
+@pytest.mark.parametrize("drain", [True, False])
+def test_submit_blocked_through_a_shutdown_is_never_lost(drain):
+    """A producer blocked on a full queue while ``shutdown`` queues its
+    stop markers: its future is served, cancelled or refused, never queued
+    behind the last marker where no worker is left to run it."""
+    g = _input_for("mis")
+    for _ in range(8):
+        eng = AmpcEngine(seed=0, max_workers=1, queue_depth=1)
+        eng._launch_lock.acquire()               # hold the worker's solve
+        futs = [eng.submit(g, "mis"), eng.submit(g, "mis")]
+
+        def producer():
+            try:
+                futs.append(eng.submit(g, "mis"))
+            except RuntimeError:
+                pass
+
+        threads = [threading.Thread(target=producer),
+                   threading.Thread(target=eng.shutdown,
+                                    kwargs={"drain": drain, "timeout": 300})]
+        threads[0].start()
+        time.sleep(0.03)                         # the producer waits ...
+        threads[1].start()
+        time.sleep(0.005)                        # ... and so does shutdown
+        eng._launch_lock.release()
+        for t in threads:
+            t.join(timeout=300)
+            assert not t.is_alive()
+        for fut in futs:
+            try:
+                assert fut.result(timeout=30).output is not None
+            except CancelledError:
+                assert not drain
+
+
 # =========================================================================
 # GraphSession snapshot reuse
 # =========================================================================
