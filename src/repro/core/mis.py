@@ -42,12 +42,34 @@ def _mis_fixpoint_masked(senders, receivers, rank, n: int, edge_ok):
     trajectory of the unpadded sequential fixpoint.  Padding vertices have
     no valid edges and resolve to IN on the first wave.
 
+    Precondition: the valid edges are symmetric — every valid (s, r) has a
+    valid (r, s).  ``UGraph.symmetric``, ``GraphBatch.padded_symmetric`` and
+    a snapshot's ``sym_*`` entries all concatenate both directions under
+    one mask.
+
     Returns (status(n,), iters, queries_nodedup, queries_dedup).
     Query accounting per wave: every undecided vertex fetches the status of
     each of its neighbours (no-dedup count); with caching each *distinct*
     neighbour is fetched once per machine — we model the per-wave dedup as
-    one fetch per distinct queried vertex (paper Section 5.3).
+    one fetch per distinct queried vertex (paper Section 5.3).  By symmetry
+    the distinct vertices queried are those with a valid edge to an
+    undecided vertex.
+
+    The waves use no scatter: indexed updates serialize per element on the
+    TPU.  Once per solve the valid edges are sorted by sender (masked lanes
+    keyed ``n``, past every vertex), so vertex v owns the contiguous
+    segment ``[bounds[v], bounds[v+1])``; each wave then gathers the
+    receivers' status once and reads every per-vertex sum as a difference
+    of prefix sums.  Only undecided vertices change, and all edges of an
+    undecided sender are scanned, so the sender's status is never gathered.
     """
+    key = jnp.where(edge_ok, senders, n).astype(jnp.int32)
+    # order inside a segment is immaterial: an unstable two-operand sort
+    # compiles in a fraction of the time of a stable or wider one
+    key, recv = jax.lax.sort((key, receivers), num_keys=1, is_stable=False)
+    lower = rank[recv] < rank[jnp.minimum(key, n - 1)]
+    bounds = jnp.searchsorted(key, jnp.arange(n + 1, dtype=jnp.int32))
+    okdeg = jnp.diff(bounds)            # valid edges per vertex
     status0 = jnp.zeros((n,), jnp.int32)
 
     def cond(s):
@@ -56,27 +78,24 @@ def _mis_fixpoint_masked(senders, receivers, rank, n: int, edge_ok):
 
     def body(s):
         status, it, q0, q1 = s
-        s_unk = (status[senders] == UNKNOWN) & edge_ok
-        lower = rank[receivers] < rank[senders]
-        # does sender have any lower-rank neighbour that is not OUT?
-        blocked = s_unk & lower & (status[receivers] != OUT)
-        has_block = jax.ops.segment_max(blocked.astype(jnp.int32), senders,
-                                        num_segments=n)
-        nbr_in = s_unk & (status[receivers] == IN)
-        has_in = jax.ops.segment_max(nbr_in.astype(jnp.int32), senders,
-                                     num_segments=n)
+        sr = status[recv]
+        # per edge: a lower-rank neighbour not OUT (blocks the sender), an
+        # IN neighbour (the sender goes OUT), an undecided neighbour (the
+        # sender is queried by it); sums over each sender's segment
+        flags = jnp.stack([lower & (sr != OUT), sr == IN, sr == UNKNOWN])
+        csum = jnp.pad(jnp.cumsum(flags.astype(jnp.int32), axis=1),
+                       ((0, 0), (1, 0)))
+        n_block, n_in, n_unk = jnp.diff(csum[:, bounds], axis=1)
         unk = status == UNKNOWN
-        status = jnp.where(unk & (has_in > 0), OUT, status)
-        status = jnp.where(unk & (has_in <= 0) & (has_block <= 0), IN, status)
+        status = jnp.where(unk & (n_in > 0), OUT, status)
+        status = jnp.where(unk & (n_in == 0) & (n_block == 0), IN, status)
         # queries: edges scanned this wave (sender undecided)
-        scanned = s_unk.sum()
-        # dedup: distinct receivers queried this wave
-        probe = jnp.zeros((n,), jnp.int32).at[
-            jnp.where(s_unk, receivers, n)].set(1, mode="drop")
-        distinct = probe.sum()
+        scanned = jnp.where(unk, okdeg, 0).sum()
+        # dedup: distinct vertices queried this wave
+        distinct = (n_unk > 0).sum()
         # gate the wave counter on this lane actually having work: under a
         # vmapped while_loop a finished batch lane may still execute the
-        # body, and the query counters are already zero then (s_unk empty)
+        # body, and the query counters are already zero then (no unk)
         live = unk.any().astype(jnp.int32)
         return status, it + live, q0 + scanned, q1 + distinct
 

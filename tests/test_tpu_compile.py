@@ -14,6 +14,7 @@ off around them (a described-chip compile can be written to it but not read
 back without a chip).
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +22,7 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.ampc.solvers import _build_mis_solver
 from repro.core import dht, msf
 from repro.kernels.dht_gather.kernel import (LANES, dht_gather_pallas,
                                              line_layout)
@@ -100,3 +102,18 @@ def test_truncated_prim_keeps_adjacency_dense(one_chip):
     print(f"truncated_prim nt={nt}: {mem}")
     # 0.61 GB chunked; 3.8 GB with every search live at once
     assert mem.temp_size_in_bytes < 4 * msf._PRIM_STATE_BYTES
+
+
+def test_mis_bucket_compiles_without_scatter(one_chip):
+    # a solve_many bucket of four graphs (n <= 4,096, m <= 65,536): the
+    # sort by sender compiles for the chip, and the chip's compiler puts
+    # no scatter back into the waves
+    b, nb, e = 4, 1 << 12, 1 << 17
+    ids = _spec(one_chip, (b, e), jnp.int32)
+    compiled = _build_mis_solver(nb).lower(
+        ids, ids, _spec(one_chip, (b, nb), jnp.float32),
+        _spec(one_chip, (b, e), jnp.bool_)).compile()
+    mem = compiled.memory_analysis()
+    print(f"mis bucket: {mem}")
+    assert not re.search(r"\sscatter\(", compiled.as_text())
+    assert mem.temp_size_in_bytes < 1 << 26
