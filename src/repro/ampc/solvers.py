@@ -212,10 +212,11 @@ def mm_ampc(g: UGraph, seed: int = 0,
                 jrank = jnp.asarray(erank)
 
     with ledger.shuffle("IsInMM", m):
-        estatus_dev, iters, q0, q1 = _mm_fixpoint(
+        estatus_dev, iters, q0, q1, steps, steps_run = _mm_fixpoint(
             u, v, jrank, n, jnp.zeros((m,), jnp.int32))
         out_dev = _collect_dev(dht, ledger, estatus_dev)
-        estatus, it, qn, qd = ledger.harvest((out_dev, iters, q0, q1))
+        estatus, it, qn, qd, steps_h, steps_run_h = ledger.harvest(
+            (out_dev, iters, q0, q1, steps, steps_run))
         with ledger.host("assemble"):
             estatus = np.asarray(estatus)
             it, qn, qd = int(it), int(qn), int(qd)
@@ -224,7 +225,8 @@ def mm_ampc(g: UGraph, seed: int = 0,
         ledger.record_queries(queries, queries * 12, waves=it,
                               deduped_away=(qn - qd) if caching else 0)
         stats = {"fixpoint_iters": it, "queries_nodedup": qn,
-                 "queries_dedup": qd, "erank": erank}
+                 "queries_dedup": qd, "erank": erank,
+                 **_lane_stats(steps_h, steps_run_h, "mm")}
         if snap_stat is not None:
             stats["snapshot"] = snap_stat
     return estatus == IN, stats
@@ -243,6 +245,7 @@ def mm_ampc_levels(g: UGraph, seed: int = 0,
     jrank = jnp.asarray(erank01.astype(np.float32))
     estatus = jnp.zeros((m,), jnp.int32)
     level_stats = []
+    steps_h = steps_run_h = 0
     ten_log_n = 10 * np.log(max(n, 2))
     for i in range(1, k + 1):
         # current degree of the residual graph
@@ -261,7 +264,7 @@ def mm_ampc_levels(g: UGraph, seed: int = 0,
         in_h = jnp.asarray(erank01 <= thresh) & unk
         with ledger.shuffle(f"level_{i}_greedyMM", nbytes_of(g.edges)):
             # resolve the sampled subgraph completely (one AMPC launch)
-            st, iters, q0, q1 = _mm_fixpoint(
+            st, iters, _, _, steps, steps_run = _mm_fixpoint(
                 u, v, jnp.where(in_h, jrank, BIGF), n,
                 jnp.where(in_h, jnp.int32(UNKNOWN), jnp.int32(OUT)))
             # edges of H_i resolved; commit IN edges, kill touched vertices
@@ -273,12 +276,15 @@ def mm_ampc_levels(g: UGraph, seed: int = 0,
             dead = (estatus == UNKNOWN) & ((matched[u] == 1) | (matched[v] == 1))
             estatus = jnp.where(dead, OUT, estatus)
             # H_i \ M_i edges whose endpoints survive go back to G_{i+1}
+        it, steps, steps_run = jax.device_get((iters, steps, steps_run))
+        steps_h += int(steps)
+        steps_run_h += int(steps_run)
         level_stats.append({"level": i, "delta": cur_delta,
-                            "threshold": thresh,
-                            "iters": int(jax.device_get(iters))})
+                            "threshold": thresh, "iters": int(it)})
     st = np.asarray(jax.device_get(estatus))
     return st == IN, {"levels": level_stats, "k": k,
-                      "erank": erank01.astype(np.float32)}
+                      "erank": erank01.astype(np.float32),
+                      **_lane_stats(steps_h, steps_run_h, "mm")}
 
 
 def mm_ampc_vertex_process(g: UGraph, epsilon: float = 0.5, seed: int = 0,
@@ -375,12 +381,13 @@ def mwm_greedy_ampc(g: UGraph, seed: int = 0,
     weight-derived ranks injected through ``mm_ampc(erank=...)``.
     Returns (in_matching bool(m,), stats)."""
     assert g.weights is not None
-    rng = np.random.default_rng(seed)
-    tie = rng.permutation(g.m).astype(np.float64) / max(g.m, 1)
-    # rank: ascending = processed first => sort by decreasing weight
-    order = np.argsort(np.lexsort((tie, -g.weights.astype(np.float64))))
-    erank = order.astype(np.float32)
     ledger = ledger if ledger is not None else RoundLedger("ampc_mwm")
+    with ledger.host("rank"):
+        rng = np.random.default_rng(seed)
+        tie = rng.permutation(g.m).astype(np.float64) / max(g.m, 1)
+        # rank: ascending = processed first => sort by decreasing weight
+        order = np.argsort(np.lexsort((tie, -g.weights.astype(np.float64))))
+        erank = order.astype(np.float32)
     in_mm, st = mm_ampc(g, seed=seed, ledger=ledger, erank=erank, dht=dht,
                         snapshot=snapshot)
     w = float(g.weights[in_mm].sum())
@@ -431,13 +438,15 @@ def _msf_assemble(orig_eid, m, dmask, found_h, q_h, jump_h, live_h, phases_h,
     return mask, stats
 
 
-def _lane_stats(lane_steps_h, lane_steps_run_h) -> dict:
-    """Truncated Prim's lane counters (per chunk, see
-    ``truncated_prim_capped``) summed into ``stats`` keys: the search
-    loop's useful lane-iterations and all it ran."""
-    return {"prim_lane_steps": int(np.sum(lane_steps_h, dtype=np.int64)),
-            "prim_lane_steps_run": int(np.sum(lane_steps_run_h,
-                                              dtype=np.int64))}
+def _lane_stats(lane_steps_h, lane_steps_run_h, kind: str = "prim") -> dict:
+    """A loop's lane counters summed into ``stats`` keys
+    ``<kind>_lane_steps`` (the lane-iterations that did useful work) and
+    ``<kind>_lane_steps_run`` (all it ran): truncated Prim's per chunk (see
+    ``truncated_prim_capped``), the matching fixpoint's per launch (see
+    ``_mm_fixpoint``)."""
+    return {f"{kind}_lane_steps": int(np.sum(lane_steps_h, dtype=np.int64)),
+            f"{kind}_lane_steps_run": int(np.sum(lane_steps_run_h,
+                                                 dtype=np.int64))}
 
 
 def msf_ampc(g: UGraph, epsilon: float = 0.5, seed: int = 0,
@@ -1077,14 +1086,14 @@ def _mm_batched_launch(bctx, batch, eranks, caching: bool = True):
     solver, hit = bctx.cache.get_or_build(
         key, lambda: _build_mm_solver(nb), occupants=B)
     t0 = time.perf_counter()
-    estatus_b, iters_b, q0_b, q1_b = solver(
+    estatus_b, iters_b, q0_b, q1_b, steps_b, steps_run_b = solver(
         jnp.asarray(u), jnp.asarray(v), jnp.asarray(ranks),
         jnp.asarray(estatus0))
     keys = np.broadcast_to(np.arange(mb, dtype=np.int32), (B, mb))
     out_b = bctx.dht.lookup_many(estatus_b, keys, ledgers=bctx.ledgers,
                                  key_mask=batch.edge_mask)
-    estatus_h, iters, q0, q1 = harvest_many(
-        bctx.ledgers, (out_b, iters_b, q0_b, q1_b))
+    estatus_h, iters, q0, q1, steps_h, steps_run_h = harvest_many(
+        bctx.ledgers, (out_b, iters_b, q0_b, q1_b, steps_b, steps_run_b))
     estatus_h = np.asarray(estatus_h)
     dt = time.perf_counter() - t0
     outs = []
@@ -1099,6 +1108,7 @@ def _mm_batched_launch(bctx, batch, eranks, caching: bool = True):
         outs.append((estatus == IN,
                      {"fixpoint_iters": it, "queries_nodedup": qn,
                       "queries_dedup": qd, "erank": eranks[b],
+                      **_lane_stats(steps_h[b], steps_run_h[b], "mm"),
                       "solver_cache": _cache_stat(key, hit, b)}))
     return outs
 

@@ -34,7 +34,8 @@ REPO = Path(__file__).resolve().parents[1]
 
 def _graph(problem):
     g = gen.rmat(7, 5.0, seed=3)
-    return g.with_random_weights(4) if problem == "msf" else g
+    weighted = problem in ("msf", "weighted-matching")
+    return g.with_random_weights(4) if weighted else g
 
 
 def _solve(eng, problem):
@@ -72,6 +73,17 @@ TREES = [
                       "host:to_device": "shuffle:PrimSearch",
                       "device:wait": "shuffle:ForestConnectivity",
                       "host:assemble": "solve"}),
+    ("matching", {"wait:launch_lock": "solve",
+                  "host:rank": "solve",
+                  "host:to_device": "shuffle:SortEdges+WriteKV",
+                  "device:wait": "shuffle:IsInMM",
+                  "host:assemble": "solve"}),
+    # the weight ranks' lexsort is labelled like every other rank draw
+    ("weighted-matching", {"wait:launch_lock": "solve",
+                           "host:rank": "solve",
+                           "host:to_device": "shuffle:SortEdges+WriteKV",
+                           "device:wait": "shuffle:IsInMM",
+                           "host:assemble": "solve"}),
 ]
 
 
